@@ -236,7 +236,7 @@ def kernel_paths() -> List[HotPath]:
 
     def flash_packed_jx():
         # packed multi-document batch: segment masking active in forward
-        # AND all three backward kernels
+        # AND both backward kernels
         q = SDS((2, 512, 4, 64), jnp.float32)
         kv = SDS((2, 512, 2, 64), jnp.float32)
         seg = SDS((2, 512), jnp.int32)

@@ -24,10 +24,7 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
 import jax.numpy as jnp
 from jax.extend.core import ClosedJaxpr, Jaxpr
 
-try:  # source provenance: private but stable across the supported range
-    from jax._src import source_info_util as _siu
-except ImportError:  # pragma: no cover - provenance degrades gracefully
-    _siu = None  # type: ignore[assignment]
+from jax._src import source_info_util as _siu  # source provenance
 
 #: Primitives that move/view/re-type data without computing on it — the
 #: propagation set for ``marked_walk``: a value is "derived from" a seed
@@ -77,12 +74,7 @@ def iter_eqns(jaxpr: Any, *, enter_pallas: bool = True) -> Iterator[Any]:
 # ------------------------------------------------------------ provenance --
 def eqn_frame(eqn: Any) -> Optional[Tuple[str, int]]:
     """(file_name, line) of the user frame an equation was traced from."""
-    if _siu is None:
-        return None
-    try:
-        fr = _siu.user_frame(eqn.source_info)
-    except Exception:
-        return None
+    fr = _siu.user_frame(eqn.source_info.traceback)
     if fr is None:
         return None
     return str(fr.file_name), int(fr.start_line)
@@ -264,14 +256,10 @@ class PallasCallInfo:
 
 
 def _block_dim(d: Any) -> int:
-    """Block dims may be ints or pallas wrapper objects; ``None`` marks a
-    squeezed/unblocked dim (extent 1)."""
-    if d is None:
-        return 1
-    try:
-        return int(d)
-    except (TypeError, ValueError):
-        return 1
+    """Block dims are ``Blocked(block_size=n)`` objects (or ints); ``None``
+    and ``Squeezed()`` mark a squeezed dim (extent 1)."""
+    d = getattr(d, "block_size", d)
+    return 1 if d is None or not isinstance(d, int) else d
 
 
 def pallas_calls(jaxpr: Any) -> List[PallasCallInfo]:
@@ -286,7 +274,7 @@ def pallas_calls(jaxpr: Any) -> List[PallasCallInfo]:
         n_out = int(getattr(gm, "num_outputs", len(eqn.outvars)))
         blocks = []
         for i, bm in enumerate(bms):
-            asd = bm.array_shape_dtype
+            asd = bm.array_aval
             blocks.append(BlockInfo(
                 block_shape=tuple(_block_dim(d) for d in bm.block_shape),
                 array_shape=tuple(int(s) for s in asd.shape),

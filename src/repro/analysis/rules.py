@@ -35,9 +35,12 @@ DTYPE_WHITELIST = ("repro/kernels",)
 #: (double-buffered). TPU v4/v5 cores carry 16 MiB of VMEM.
 VMEM_LIMIT_BYTES = 16 * 2 ** 20
 VMEM_WARN_FRAC = 0.9
+#: (sublane, lane) tile the last two dims of a TPU block must respect
+TPU_TILE = (8, 128)
 
 HOST_SYNC_PRIMS = frozenset({
-    "callback", "debug_callback", "infeed", "io_callback", "outfeed",
+    "callback", "debug_callback", "debug_print", "infeed", "io_callback",
+    "outfeed",
     "outside_call", "pure_callback",
 })
 TRANSFER_PRIMS = frozenset({"device_put"})
@@ -225,13 +228,21 @@ def _check_r4(path: Any) -> List[Finding]:
 
 
 # ------------------------------------------------------------------- R5 --
+def _tpu_tiled(b: Any) -> bool:
+    """Mosaic's block rule: each of the last two block dims is a multiple
+    of the (sublane, lane) tile or the whole array dim."""
+    return all(bd == ad or bd % t == 0 for bd, ad, t in
+               zip(b.block_shape[-2:], b.array_shape[-2:], TPU_TILE))
+
+
 def pallas_findings(jaxpr: Any,
                     vmem_limit: int = VMEM_LIMIT_BYTES
                     ) -> List[Tuple[str, str, str]]:
     """(severity, locus, message) per pallas_call: double-buffered
     BlockSpec working set vs the VMEM budget, block/array divisibility
     (a block extent that does not tile its array dim reads/writes a
-    partial tile every grid step), and output grid coverage (grid x block
+    partial tile every grid step), the TPU (8, 128) block rule, and output
+    grid coverage (grid x block
     must reach every output element — an undersized grid silently leaves
     output regions unwritten)."""
     out: List[Tuple[str, str, str]] = []
@@ -257,6 +268,13 @@ def pallas_findings(jaxpr: Any,
                         f"block {b.block_shape} does not tile array "
                         f"{b.array_shape}: {ad} % {bd} != 0"))
                     break
+            if len(b.array_shape) >= 2 and not _tpu_tiled(b):
+                out.append((
+                    "error", call.locus,
+                    f"block {b.block_shape} over array {b.array_shape}: "
+                    "the last two block dims must be multiples of "
+                    f"{TPU_TILE} or equal the array dims (Mosaic refuses "
+                    "it; interpret mode does not)"))
         for b in call.blocks:
             if not b.is_output:
                 continue

@@ -26,7 +26,34 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from repro.core.precision import TriAccelConfig
+
+#: The CPU backend reports no memory limit; its (test) path models one
+#: 16 GB device. Accelerators must report their own.
+CPU_MEM_CAP = 16e9
+
+
+def device_mem_cap(device) -> float:
+    """Per-device memory limit as ``device`` reports it
+    (``memory_stats()["bytes_limit"]``). An accelerator that reports none
+    is an error, not a default."""
+    stats = device.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return float(stats["bytes_limit"])
+    if device.platform == "cpu":
+        return CPU_MEM_CAP
+    raise RuntimeError(
+        f"{device.platform} device {device.device_kind!r} reports no "
+        "memory_stats()['bytes_limit']; pass mem_cap_bytes explicitly")
+
+
+def with_device_cap(cfg: TriAccelConfig, device) -> TriAccelConfig:
+    """``cfg`` with ``mem_cap_bytes`` filled from ``device`` when unset."""
+    if cfg.mem_cap_bytes is not None:
+        return cfg
+    return dataclasses.replace(cfg, mem_cap_bytes=device_mem_cap(device))
 
 
 def measured_exe_bytes(compiled) -> Optional[float]:
@@ -34,10 +61,7 @@ def measured_exe_bytes(compiled) -> Optional[float]:
     ``memory_analysis()``: temp + argument + output + generated code, with
     donated (aliased) buffers counted once. ``None`` when the backend
     reports nothing (the caller falls back to the analytic model)."""
-    try:
-        mem = compiled.memory_analysis()
-    except Exception:
-        return None
+    mem = compiled.memory_analysis()
     if mem is None:
         return None
     fields = ("temp_size_in_bytes", "argument_size_in_bytes",
@@ -111,7 +135,8 @@ class MemoryModel:
         if measured_bytes <= 0:
             return
         est = self.total(tokens_per_device, codes, ladder) / self.calibration
-        if est > 0:
+        # a subnormal measurement can underflow the ratio to 0 as well
+        if est > 0 and measured_bytes / est > 0:
             self.calibration = measured_bytes / est
 
     # ------------------------------------------- measured-bytes overlay ---
@@ -170,6 +195,8 @@ class BatchScaler:
     def __init__(self, rungs: Sequence[int], seq_len: int, model: MemoryModel,
                  cfg: TriAccelConfig, start_rung: Optional[int] = None):
         assert list(rungs) == sorted(set(rungs)) and len(rungs) > 0
+        if cfg.mem_cap_bytes is None:
+            cfg = with_device_cap(cfg, jax.devices()[0])
         self.rungs = list(rungs)
         self.seq_len = seq_len
         self.model = model

@@ -53,13 +53,25 @@ class TriAccelConfig:
     rho_high: float = 0.92
     delta_up: int = 1                   # rung steps, paper's delta_up/down
     delta_down: int = 1
-    mem_cap_bytes: float = 16e9         # per-device HBM (v5e)
+    #: per-device memory cap; None = the device's own limit
+    #: (batch_scaler.device_mem_cap)
+    mem_cap_bytes: Optional[float] = None
     # §3.4 control loop
     t_ctrl: int = 50
     # ablation switches (paper Table 2)
     enable_precision: bool = True
     enable_curvature: bool = True
     enable_batch: bool = True
+
+
+def check_ladder_kernels(ladder: str, platform: str) -> None:
+    """The gpu ladder's fp16 low tier has no TPU kernel: Mosaic cannot
+    lower the f32 -> f16 cast (``tpu.pack_subelements``). Refuse at
+    construction, before any compile, instead of switching tiers."""
+    if ladder == "gpu" and platform == "tpu":
+        raise ValueError(
+            "ladder='gpu' (fp16 low tier) has no Pallas TPU lowering: Mosaic "
+            "cannot cast to float16. Use ladder='tpu' (fp8 low tier) on TPU.")
 
 
 # ------------------------------------------------------------------ QDQ ----
@@ -71,12 +83,24 @@ def _qdq_bf16(x: jax.Array) -> jax.Array:
     return x.astype(jnp.bfloat16).astype(x.dtype)
 
 
+@jax.custom_jvp
 def _qdq_fp8(x: jax.Array) -> jax.Array:
-    """Per-tensor amax-scaled e4m3 rounding (TPU-native low tier)."""
+    """Per-tensor amax-scaled e4m3 rounding (TPU-native low tier).
+
+    Straight-through: the derivative is the identity. Differentiating the
+    casts themselves would round the COTANGENT onto the unscaled e4m3 grid
+    (no inf, NaN above 448, zero below 2^-9), so every low-tier step would
+    come back non-finite and be skipped; the fused path differentiates
+    against the already-rounded compute copy, which is this same rule."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)))
     scale = jnp.where(amax > 0, FP8_MAX / amax, 1.0)
     y = (x.astype(jnp.float32) * scale).astype(jnp.float8_e4m3fn)
     return (y.astype(jnp.float32) / scale).astype(x.dtype)
+
+
+@_qdq_fp8.defjvp
+def _qdq_fp8_jvp(primals, tangents):
+    return _qdq_fp8(primals[0]), tangents[0]
 
 
 def _identity(x: jax.Array) -> jax.Array:
@@ -86,8 +110,10 @@ def _identity(x: jax.Array) -> jax.Array:
 def qdq(x: jax.Array, code: jax.Array, ladder: str = "gpu") -> jax.Array:
     """Round ``x`` to the grid of the precision tier selected by ``code``.
 
-    Gradients pass straight through the rounding (convert_element_type is
-    linear in JAX), matching mixed-precision master-weight semantics.
+    Gradients pass straight through the rounding, matching mixed-precision
+    master-weight semantics: the fp16/bf16 casts are linear in JAX (the
+    cotangent takes the tier's dtype), the fp8 tier is straight-through by
+    its custom derivative.
     """
     if not jnp.issubdtype(x.dtype, jnp.floating):
         return x

@@ -8,7 +8,8 @@ grad_stats.py      — one-pass fused sum / sum-of-squares / absmax reduction
                      small-tile path for sub-block leaves
 flash_attention.py — block-tiled online-softmax attention with causal +
                      sliding-window block skipping (the LM hot spot),
-                     forward AND backward (dO·O / dQ / dK-dV kernels)
+                     forward AND backward (dQ / dK-dV kernels), plus the
+                     ragged per-slot-length decode kernel
 fused_update.py    — the whole post-backward update phase as two slab
                      sweeps: per-layer stats + finite + norm (phase 1),
                      then clip + optimizer + fp32 master write + next-step

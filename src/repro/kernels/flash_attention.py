@@ -12,6 +12,16 @@ and ~sum_doc(len_doc^2)/2 for packed attention, unlike the chunked-jnp path
 which computes every pair and masks. GQA is handled in the k/v index_map
 (q head h reads kv head h // rep) so k/v are never materialized per q-head.
 
+Layout. The public API takes the model's (B, S, H, D) tensors; the kernels
+run on a heads-major (B, H, S, D) view, because Mosaic tiles the LAST TWO
+block dims onto (sublane, lane) and requires them to be multiples of (8,
+128) or the full array dims: a (BQ, D) tile satisfies that, a per-head
+block of the (B, S, H, D) array (a 1 over H in the second-minor place)
+does not. The per-row softmax residuals (logsumexp, and the backward's
+D_i = sum_d dO_id * O_id) are stored lane-major as (B, H, 1, S), so the
+backward kernels take scores TRANSPOSED — (BK, BQ) = k @ q^T — and
+broadcast the residual rows across sublanes without any relayout.
+
 Segment masking (packed multi-document rows): ``segments`` is a (B, S)
 int32 array of NON-DECREASING per-row document ids; attention never crosses
 a segment boundary. Positions are the within-segment arange, so within a
@@ -20,37 +30,44 @@ kernels keep masking on the global iota (causal/window) and add one
 equality term (q_seg == k_seg). Because ids are sorted per row, a tile is
 skippable exactly when its q/k segment-id ranges do not overlap — a
 runtime predicate folded into the same ``pl.when`` as the causal/window
-skip, so forward and all three backward kernels skip identical blocks.
+skip, so forward and both backward kernels skip identical blocks. The ids
+reach the kernels twice: as (B, S, 1) columns and (B, 1, S) rows, so each
+kernel reads the orientation its score tile needs.
 
 The value head dim (Dv) is tiled independently of the q/k head dim (D):
 MLA training (qk = nope+rope dim, v = v_head_dim) runs these kernels with
 q/k (…, D) and v/o (…, Dv) BlockSpecs.
 
-Training runs four kernels (FlashAttention-2 style; DESIGN.md §8, §14):
+Training runs three kernels (FlashAttention-2 style; DESIGN.md §8, §14):
 
   * forward (``flash_attention_fwd``) — the inference forward plus one
-    (B, H, S) fp32 logsumexp residual, the ONLY extra tensor the backward
-    needs beyond q/k/v/o (no (S, S) probabilities are ever materialized);
-  * ``_delta_kernel`` — preprocessing pass D_i = sum_d dO_id * O_id;
+    fp32 logsumexp residual, the ONLY extra tensor the backward needs
+    beyond q/k/v/o (no (S, S) probabilities are ever materialized);
   * ``_dq_kernel`` — dQ, one q-tile accumulator swept over k-blocks
     (same grid walk as the forward, same block skipping);
   * ``_dkv_kernel`` — dK and dV, one k-tile accumulator pair swept over the
     GQA head group x q-blocks, so grouped q-heads accumulate into their
     shared kv head without materializing per-q-head k/v gradients.
 
-All four share ``_block_needed``/``_tile_mask``, so forward and backward
-skip exactly the same blocks. ``kernels.ops`` binds fwd+bwd into one
-differentiable op with ``jax.custom_vjp`` behind the dispatch gate.
+D_i is one fused XLA reduction ahead of them. All kernels share
+``_block_needed``/``_tile_mask``, so forward and backward skip exactly the
+same blocks. ``kernels.ops`` binds fwd+bwd into one differentiable op with
+``jax.custom_vjp`` behind the dispatch gate.
 
-``flash_decode`` is the serving-side ragged kernel: one query row per
-(b, h) against a (B, L, K, D) cache plus a (B,) int32 length vector
-prefetched as a scalar operand (``pltpu.PrefetchScalarGridSpec``), so the
-k-block loop stops at ceil(len/BD) per row — the k/v index_map CLAMPS the
-block index to the last needed block (skipped steps re-address the same
-tile, so no new DMA is issued) and ``pl.when`` skips their compute. Decode
-HBM reads therefore scale with the actual sequence length, not the cache
-capacity. Lengths are a traced runtime operand: one compiled executable
-serves every slot-length pattern (zero recompiles after serve warm()).
+``flash_decode`` is the serving-side ragged kernel: one query row per head
+against a (B, L, K, D) cache plus a (B,) int32 length vector prefetched as
+a scalar operand (``pltpu.PrefetchScalarGridSpec``), so the k-block loop
+stops at ceil(len/BD) per row — the k/v index_map CLAMPS the block index to
+the last needed block (skipped steps re-address the same tile, so no new
+DMA is issued) and ``pl.when`` skips their compute. Each grid step reads
+one (BD, K*D) slab of the cache — every kv head of BD slots, the cache's
+own memory order — and scores all H query heads against it in one matmul
+with a block-diagonal query (head h holds its vector in kv head h // rep's
+lanes and zeros elsewhere). Decode HBM reads therefore scale with the
+actual sequence length, not the cache capacity, and each cache byte is
+read once per row rather than once per query head. Lengths are a traced
+runtime operand: one compiled executable serves every slot-length pattern
+(zero recompiles after serve warm()).
 
 Shapes: q (B, S, H, D); k (B, S, K, D); v (B, S, K, Dv); H % K == 0;
 S % BQ == S % BK == 0. VMEM at defaults (BQ=BK=256, D<=256 fp32): ~1.5 MiB
@@ -72,46 +89,78 @@ BK = 256
 #: the cache length wins; < 8 would break TPU sublane tiling -> no kernel)
 DECODE_BLOCKS = (256, 128, 64, 32, 16, 8)
 
+# (M, K) x (N, K) -> (M, N): contract the lane dims of both operands
+_NT = (((1,), (1,)), ((), ()))
+# (K, M) x (K, N) -> (M, N): contract the sublane dims of both operands
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _heads_major(x):
+    """(B, S, H, D) <-> (B, H, S, D)."""
+    return jnp.swapaxes(x, 1, 2)
+
 
 def _block_needed(q_start, k_start, causal: bool, window: int,
                   sq=None, sk=None):
     """Does tile (q_start, k_start) contain ANY unmasked (q, k) pair? Shared
     by forward and both backward kernels so all skip identical blocks.
-    ``sq``/``sk`` are the tile's (BQ,)/(BK,) segment-id rows (non-decreasing
-    within a row), making the predicate runtime-valued for packed batches:
-    a tile whose segment ranges do not overlap is fully cross-document."""
+    ``sq``/``sk`` are the tile's q/k segment ids (non-decreasing along the
+    sequence, in either orientation), making the predicate runtime-valued
+    for packed batches: a tile whose segment ranges do not overlap is fully
+    cross-document."""
     needed = jnp.asarray(True)
     if causal:
         needed = needed & (k_start <= q_start + BQ - 1)
     if window and window > 0:
         needed = needed & (k_start + BK - 1 >= q_start - (window - 1))
     if sq is not None:
-        needed = needed & (sq[-1] >= sk[0]) & (sq[0] <= sk[-1])
+        needed = needed & (jnp.max(sq) >= jnp.min(sk)) & \
+            (jnp.min(sq) <= jnp.max(sk))
     return needed
 
 
-def _tile_mask(q_start, k_start, causal: bool, window: int, sq=None, sk=None):
-    """(BQ, BK) bool mask of valid pairs inside one tile. With segments,
+def _tile_mask(q_start, k_start, causal: bool, window: int, sq=None, sk=None,
+               transposed: bool = False):
+    """Bool mask of valid pairs inside one tile: (BQ, BK), or (BK, BQ) with
+    ``transposed`` (then ``sk`` is a (BK, 1) column and ``sq`` a (1, BQ)
+    row; otherwise ``sq`` is the column and ``sk`` the row). With segments,
     positions are the within-segment arange, so the global-iota causal and
     window terms are exact inside a segment and the segment equality term
     kills every cross-document pair."""
-    qp = q_start + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
-    kp = k_start + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
+    shape = (BK, BQ) if transposed else (BQ, BK)
+    qd, kd = (1, 0) if transposed else (0, 1)
+    qp = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, qd)
+    kp = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, kd)
     d = qp - kp
-    ok = jnp.ones((BQ, BK), jnp.bool_)
+    ok = jnp.ones(shape, jnp.bool_)
     if causal:
         ok = ok & (d >= 0)
     if window and window > 0:
         ok = ok & (d < window)
     if sq is not None:
-        ok = ok & (sq[:, None] == sk[None, :])
+        ok = ok & (sq == sk)
     return ok
 
 
+def _seg_views(segments):
+    """(B, S) ids -> ((B, S, 1) columns, (B, 1, S) rows)."""
+    seg = segments.astype(jnp.int32)
+    B, S = seg.shape
+    return seg.reshape(B, S, 1), seg.reshape(B, 1, S)
+
+
 # ================================================================ forward ==
-def _fwd_body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc_ref,
-              m_ref, l_ref, *, causal: bool, window: int, scale: float,
-              nk: int):
+def _fwd_kernel(*refs, causal: bool, window: int, scale: float, nk: int,
+                seg: bool, with_lse: bool):
+    q_ref, k_ref, v_ref = refs[:3]
+    rest = refs[3:]
+    sq_ref = sk_ref = lse_ref = None
+    if seg:
+        sq_ref, sk_ref, *rest = rest
+    o_ref, *rest = rest
+    if with_lse:
+        lse_ref, *rest = rest
+    acc_ref, m_ref, l_ref = rest
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -123,63 +172,35 @@ def _fwd_body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc_ref,
 
     q_start = qi * BQ
     k_start = ki * BK
-    sq = None if sq_ref is None else sq_ref[0, :]
-    sk = None if sk_ref is None else sk_ref[0, :]
+    sq = None if sq_ref is None else sq_ref[...]              # (BQ, 1)
+    sk = None if sk_ref is None else sk_ref[...]              # (1, BK)
 
     @pl.when(_block_needed(q_start, k_start, causal, window, sq, sk))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (BQ, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (BK, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)              # (BK, Dv)
-        s = q @ k.T                                            # (BQ, BK)
+        q = q_ref[...].astype(jnp.float32) * scale             # (BQ, D)
+        k = k_ref[...].astype(jnp.float32)                     # (BK, D)
+        v = v_ref[...].astype(jnp.float32)                     # (BK, Dv)
+        s = jax.lax.dot_general(q, k, _NT)                     # (BQ, BK)
         s = jnp.where(_tile_mask(q_start, k_start, causal, window, sq, sk),
                       s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                    # (BQ, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(p, v)
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
         if lse_ref is not None:
             # logsumexp over the row's valid scores: the one residual the
-            # backward rebuilds p from (p = exp(s - lse))
-            lse_ref[0, 0, :] = m_ref[...] + jnp.log(l)
-
-
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, **kw):
-    _fwd_body(q_ref, k_ref, v_ref, None, None, o_ref, None, acc_ref, m_ref,
-              l_ref, **kw)
-
-
-def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                      l_ref, **kw):
-    _fwd_body(q_ref, k_ref, v_ref, None, None, o_ref, lse_ref, acc_ref,
-              m_ref, l_ref, **kw)
-
-
-def _flash_kernel_seg(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref,
-                      m_ref, l_ref, **kw):
-    _fwd_body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, None, acc_ref,
-              m_ref, l_ref, **kw)
-
-
-def _flash_kernel_seg_lse(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref,
-                          lse_ref, acc_ref, m_ref, l_ref, **kw):
-    _fwd_body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref, acc_ref,
-              m_ref, l_ref, **kw)
-
-
-def _seg_specs():
-    """BlockSpecs of the (B, S) int32 segment-id array on the fwd/dq grid
-    (b, h, qi, ki): one (1, BQ) q row tile and one (1, BK) k row tile."""
-    return [pl.BlockSpec((1, BQ), lambda b, h, qi, ki: (b, qi)),
-            pl.BlockSpec((1, BK), lambda b, h, qi, ki: (b, ki))]
+            # backward rebuilds p from (p = exp(s - lse)). Stored lane-major:
+            # the (BQ, 1) column goes through one (BQ, 128) transpose.
+            lse = jnp.broadcast_to(m_ref[...] + jnp.log(l), (BQ, 128))
+            lse_ref[...] = jnp.transpose(lse)[0:1, :]
 
 
 def _fwd_call(q, k, v, segments, *, causal, window, scale, interpret,
@@ -192,47 +213,46 @@ def _fwd_call(q, k, v, segments, *, causal, window, scale, interpret,
     if scale is None:
         scale = D ** -0.5
     nq, nk = S // BQ, S // BK
-    grid = (B, H, nq, nk)
-    kw = dict(causal=causal, window=int(window or 0), scale=float(scale),
-              nk=nk)
-    if segments is None:
-        kern = _flash_kernel_lse if with_lse else _flash_kernel
-    else:
-        kern = _flash_kernel_seg_lse if with_lse else _flash_kernel_seg
-    kern = functools.partial(kern, **kw)
+    seg = segments is not None
+    kern = functools.partial(
+        _fwd_kernel, causal=causal, window=int(window or 0),
+        scale=float(scale), nk=nk, seg=seg, with_lse=with_lse)
     in_specs = [
-        pl.BlockSpec((1, BQ, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-        pl.BlockSpec((1, BK, 1, D),
-                     lambda b, h, qi, ki: (b, ki, h // rep, 0)),
-        pl.BlockSpec((1, BK, 1, Dv),
-                     lambda b, h, qi, ki: (b, ki, h // rep, 0)),
+        pl.BlockSpec((None, None, BQ, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+        pl.BlockSpec((None, None, BK, D),
+                     lambda b, h, qi, ki: (b, h // rep, ki, 0)),
+        pl.BlockSpec((None, None, BK, Dv),
+                     lambda b, h, qi, ki: (b, h // rep, ki, 0)),
     ]
-    args = [q, k, v]
-    if segments is not None:
-        in_specs += _seg_specs()
-        args.append(segments.astype(jnp.int32))
-        args.append(args[-1])
-    out_shape = [jax.ShapeDtypeStruct((B, S, H, Dv), q.dtype)]
-    out_specs = [pl.BlockSpec((1, BQ, 1, Dv),
-                              lambda b, h, qi, ki: (b, qi, h, 0))]
+    args = [_heads_major(q), _heads_major(k), _heads_major(v)]
+    if seg:
+        col, row = _seg_views(segments)
+        in_specs += [
+            pl.BlockSpec((None, BQ, 1), lambda b, h, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((None, 1, BK), lambda b, h, qi, ki: (b, 0, ki))]
+        args += [col, row]
+    out_shape = [jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype)]
+    out_specs = [pl.BlockSpec((None, None, BQ, Dv),
+                              lambda b, h, qi, ki: (b, h, qi, 0))]
     if with_lse:
-        out_shape.append(jax.ShapeDtypeStruct((B, H, S), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, BQ),
-                                      lambda b, h, qi, ki: (b, h, qi)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
+        out_specs.append(pl.BlockSpec((None, None, 1, BQ),
+                                      lambda b, h, qi, ki: (b, h, 0, qi)))
     res = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((BQ, Dv), jnp.float32),
-            pltpu.VMEM((BQ,), jnp.float32),
-            pltpu.VMEM((BQ,), jnp.float32),
+            pltpu.VMEM((BQ, 1), jnp.float32),
+            pltpu.VMEM((BQ, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    return tuple(res) if with_lse else (res[0],)
+    o = _heads_major(res[0])
+    return (o, res[1]) if with_lse else (o,)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -250,21 +270,38 @@ def flash_attention(q, k, v, segments=None, *, causal: bool = True,
 def flash_attention_fwd(q, k, v, segments=None, *, causal: bool = True,
                         window: int = 0, scale: float = None,
                         interpret: bool = False):
-    """Training forward: returns (o, lse) with lse (B, H, S) fp32."""
+    """Training forward: returns (o, lse) with lse (B, H, 1, S) fp32."""
     return _fwd_call(q, k, v, segments, causal=causal, window=window,
                      scale=scale, interpret=interpret, with_lse=True)
 
 
 # =============================================================== backward ==
-def _delta_kernel(o_ref, do_ref, delta_ref):
-    o = o_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
-    delta_ref[0, 0, :] = jnp.sum(o * do, axis=1)
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq, sk,
+              q_start, k_start, *, causal, window, scale):
+    """Shared transposed-score tile of both backward kernels: returns the
+    pre-scaled q, k, and (pT, dsT), each (BK, BQ)."""
+    q = q_ref[...].astype(jnp.float32) * scale                 # (BQ, D)
+    k = k_ref[...].astype(jnp.float32)                         # (BK, D)
+    v = v_ref[...].astype(jnp.float32)                         # (BK, Dv)
+    do = do_ref[...].astype(jnp.float32)                       # (BQ, Dv)
+    st = jax.lax.dot_general(k, q, _NT)                        # (BK, BQ)
+    st = jnp.where(_tile_mask(q_start, k_start, causal, window, sq, sk,
+                              transposed=True), st, NEG_INF)
+    pt = jnp.exp(st - lse_ref[...])                 # masked pairs -> 0
+    dpt = jax.lax.dot_general(v, do, _NT)                      # (BK, BQ)
+    dst = pt * (dpt - delta_ref[...])
+    return q, k, do, pt, dst
 
 
-def _dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
-             dq_ref, acc_ref, *, causal: bool, window: int, scale: float,
-             nk: int):
+def _dq_kernel(*refs, causal: bool, window: int, scale: float, nk: int,
+               seg: bool):
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    rest = refs[6:]
+    sq = sk = None
+    if seg:
+        sq_ref, sk_ref, *rest = rest
+        sq, sk = sq_ref[...], sk_ref[...]                      # (1,BQ),(BK,1)
+    dq_ref, acc_ref = rest
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -274,44 +311,29 @@ def _dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
 
     q_start = qi * BQ
     k_start = ki * BK
-    sq = None if sq_ref is None else sq_ref[0, :]
-    sk = None if sk_ref is None else sk_ref[0, :]
 
     @pl.when(_block_needed(q_start, k_start, causal, window, sq, sk))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        s = q @ k.T
-        s = jnp.where(_tile_mask(q_start, k_start, causal, window, sq, sk),
-                      s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0, :][:, None])     # masked pairs -> 0
-        dp = do @ v.T
-        ds = p * (dp - delta_ref[0, 0, :][:, None])
-        acc_ref[...] += ds @ k
+        _, k, _, _, dst = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq, sk,
+            q_start, k_start, causal=causal, window=window, scale=scale)
+        acc_ref[...] += jax.lax.dot_general(dst, k, _TN)       # (BQ, D)
 
     @pl.when(ki == nk - 1)
     def _finalize():
         # s was taken against scale*q, so d/dq carries one more factor
-        dq_ref[0, :, 0, :] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, **kw):
-    _dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None, None,
-             dq_ref, acc_ref, **kw)
-
-
-def _dq_kernel_seg(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref,
-                   sk_ref, dq_ref, acc_ref, **kw):
-    _dq_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref, sk_ref,
-             dq_ref, acc_ref, **kw)
-
-
-def _dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref,
-              sk_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-              window: int, scale: float, rep: int, nq: int):
+def _dkv_kernel(*refs, causal: bool, window: int, scale: float, rep: int,
+                nq: int, seg: bool):
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    rest = refs[6:]
+    sq = sk = None
+    if seg:
+        sq_ref, sk_ref, *rest = rest
+        sq, sk = sq_ref[...], sk_ref[...]                      # (1,BQ),(BK,1)
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
     ki = pl.program_id(2)
     r = pl.program_id(3)       # q head within the GQA group of this kv head
     qi = pl.program_id(4)
@@ -323,40 +345,19 @@ def _dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref,
 
     q_start = qi * BQ
     k_start = ki * BK
-    sq = None if sq_ref is None else sq_ref[0, :]
-    sk = None if sk_ref is None else sk_ref[0, :]
 
     @pl.when(_block_needed(q_start, k_start, causal, window, sq, sk))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        s = q @ k.T                                    # (BQ, BK)
-        s = jnp.where(_tile_mask(q_start, k_start, causal, window, sq, sk),
-                      s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0, :][:, None])
-        dv_acc[...] += p.T @ do
-        dp = do @ v.T
-        ds = p * (dp - delta_ref[0, 0, :][:, None])
-        dk_acc[...] += ds.T @ q                        # q pre-scaled: dk done
+        q, _, do, pt, dst = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq, sk,
+            q_start, k_start, causal=causal, window=window, scale=scale)
+        dv_acc[...] += jnp.dot(pt, do)                         # (BK, Dv)
+        dk_acc[...] += jnp.dot(dst, q)          # q pre-scaled: dk done
 
     @pl.when((r == rep - 1) & (qi == nq - 1))
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_acc, dv_acc, **kw):
-    _dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None, None,
-              dk_ref, dv_ref, dk_acc, dv_acc, **kw)
-
-
-def _dkv_kernel_seg(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref,
-                    sk_ref, dk_ref, dv_ref, dk_acc, dv_acc, **kw):
-    _dkv_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sq_ref,
-              sk_ref, dk_ref, dv_ref, dk_acc, dv_acc, **kw)
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -373,93 +374,77 @@ def flash_attention_bwd(q, k, v, o, lse, do, segments=None, *,
     if scale is None:
         scale = D ** -0.5
     nq, nk = S // BQ, S // BK
-    kw = dict(causal=causal, window=int(window or 0), scale=float(scale))
-    seg = None if segments is None else segments.astype(jnp.int32)
-
-    delta = pl.pallas_call(
-        _delta_kernel,
-        grid=(B, H, nq),
-        in_specs=[
-            pl.BlockSpec((1, BQ, 1, Dv), lambda b, h, qi: (b, qi, h, 0)),
-            pl.BlockSpec((1, BQ, 1, Dv), lambda b, h, qi: (b, qi, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, BQ), lambda b, h, qi: (b, h, qi)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S), jnp.float32),
-        interpret=interpret,
-    )(o, do)
+    kw = dict(causal=causal, window=int(window or 0), scale=float(scale),
+              seg=segments is not None)
+    # D_i = sum_d dO_id * O_id: one fused XLA reduction, lane-major like lse
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.swapaxes(delta, 1, 2).reshape(B, H, 1, S)
+    qh, kh, vh, doh = (_heads_major(x) for x in (q, k, v, do))
+    seg_args = []
+    if segments is not None:
+        col, row = _seg_views(segments)
+        seg_args = [row, col]
 
     dq_in_specs = [
-        pl.BlockSpec((1, BQ, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-        pl.BlockSpec((1, BK, 1, D),
-                     lambda b, h, qi, ki: (b, ki, h // rep, 0)),
-        pl.BlockSpec((1, BK, 1, Dv),
-                     lambda b, h, qi, ki: (b, ki, h // rep, 0)),
-        pl.BlockSpec((1, BQ, 1, Dv), lambda b, h, qi, ki: (b, qi, h, 0)),
-        pl.BlockSpec((1, 1, BQ), lambda b, h, qi, ki: (b, h, qi)),
-        pl.BlockSpec((1, 1, BQ), lambda b, h, qi, ki: (b, h, qi)),
+        pl.BlockSpec((None, None, BQ, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+        pl.BlockSpec((None, None, BK, D),
+                     lambda b, h, qi, ki: (b, h // rep, ki, 0)),
+        pl.BlockSpec((None, None, BK, Dv),
+                     lambda b, h, qi, ki: (b, h // rep, ki, 0)),
+        pl.BlockSpec((None, None, BQ, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
+        pl.BlockSpec((None, None, 1, BQ), lambda b, h, qi, ki: (b, h, 0, qi)),
+        pl.BlockSpec((None, None, 1, BQ), lambda b, h, qi, ki: (b, h, 0, qi)),
     ]
-    dq_args = [q, k, v, do, lse, delta]
-    if seg is not None:
-        dq_in_specs += _seg_specs()
-        dq_args += [seg, seg]
+    if seg_args:
+        dq_in_specs += [
+            pl.BlockSpec((None, 1, BQ), lambda b, h, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((None, BK, 1), lambda b, h, qi, ki: (b, ki, 0))]
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel if seg is None else _dq_kernel_seg,
-                          nk=nk, **kw),
+        functools.partial(_dq_kernel, nk=nk, **kw),
         grid=(B, H, nq, nk),
         in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, BQ, 1, D),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        out_specs=pl.BlockSpec((None, None, BQ, D),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         interpret=interpret,
-    )(*dq_args)
+    )(qh, kh, vh, doh, lse, delta, *seg_args)
 
     # dk/dv: one (BK, D) accumulator pair per kv head, swept over the GQA
     # head group (r) and all q-blocks (qi) — grouped q-heads reduce into the
     # shared kv head inside VMEM, never through HBM
+    qmap = lambda b, g, ki, r, qi: (b, g * rep + r, qi, 0)
+    kmap = lambda b, g, ki, r, qi: (b, g, ki, 0)
+    rowmap = lambda b, g, ki, r, qi: (b, g * rep + r, 0, qi)
     dkv_in_specs = [
-        pl.BlockSpec((1, BQ, 1, D),
-                     lambda b, g, ki, r, qi: (b, qi, g * rep + r, 0)),
-        pl.BlockSpec((1, BK, 1, D),
-                     lambda b, g, ki, r, qi: (b, ki, g, 0)),
-        pl.BlockSpec((1, BK, 1, Dv),
-                     lambda b, g, ki, r, qi: (b, ki, g, 0)),
-        pl.BlockSpec((1, BQ, 1, Dv),
-                     lambda b, g, ki, r, qi: (b, qi, g * rep + r, 0)),
-        pl.BlockSpec((1, 1, BQ),
-                     lambda b, g, ki, r, qi: (b, g * rep + r, qi)),
-        pl.BlockSpec((1, 1, BQ),
-                     lambda b, g, ki, r, qi: (b, g * rep + r, qi)),
+        pl.BlockSpec((None, None, BQ, D), qmap),
+        pl.BlockSpec((None, None, BK, D), kmap),
+        pl.BlockSpec((None, None, BK, Dv), kmap),
+        pl.BlockSpec((None, None, BQ, Dv), qmap),
+        pl.BlockSpec((None, None, 1, BQ), rowmap),
+        pl.BlockSpec((None, None, 1, BQ), rowmap),
     ]
-    dkv_args = [q, k, v, do, lse, delta]
-    if seg is not None:
+    if seg_args:
         dkv_in_specs += [
-            pl.BlockSpec((1, BQ), lambda b, g, ki, r, qi: (b, qi)),
-            pl.BlockSpec((1, BK), lambda b, g, ki, r, qi: (b, ki)),
-        ]
-        dkv_args += [seg, seg]
+            pl.BlockSpec((None, 1, BQ), lambda b, g, ki, r, qi: (b, 0, qi)),
+            pl.BlockSpec((None, BK, 1), lambda b, g, ki, r, qi: (b, ki, 0))]
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel if seg is None else _dkv_kernel_seg,
-                          rep=rep, nq=nq, **kw),
+        functools.partial(_dkv_kernel, rep=rep, nq=nq, **kw),
         grid=(B, K, nk, rep, nq),
         in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, BK, 1, D),
-                         lambda b, g, ki, r, qi: (b, ki, g, 0)),
-            pl.BlockSpec((1, BK, 1, Dv),
-                         lambda b, g, ki, r, qi: (b, ki, g, 0)),
-        ],
+        out_specs=[pl.BlockSpec((None, None, BK, D), kmap),
+                   pl.BlockSpec((None, None, BK, Dv), kmap)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, K, D), k.dtype),
-            jax.ShapeDtypeStruct((B, S, K, Dv), v.dtype),
+            jax.ShapeDtypeStruct((B, K, S, D), k.dtype),
+            jax.ShapeDtypeStruct((B, K, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((BK, D), jnp.float32),
             pltpu.VMEM((BK, Dv), jnp.float32),
         ],
         interpret=interpret,
-    )(*dkv_args)
-    return dq, dk, dv
+    )(qh, kh, vh, doh, lse, delta, *seg_args)
+    return _heads_major(dq), _heads_major(dk), _heads_major(dv)
 
 
 # ========================================================== ragged decode ==
@@ -481,9 +466,9 @@ def decode_block(L: int):
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, scale: float, bd: int, nk: int):
+                   l_ref, *, bd: int, nk: int):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -497,24 +482,24 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     # to the last needed tile (no new DMA) and compute is skipped entirely
     @pl.when(ki * bd < length)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (1, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bd, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)              # (bd, Dv)
-        s = q @ k.T                                            # (1, bd)
-        slot = ki * bd + jax.lax.broadcasted_iota(jnp.int32, (1, bd), 1)
+        q = q_ref[...].astype(jnp.float32)             # (Hp, K*D), scaled
+        k = k_ref[...].astype(jnp.float32)             # (bd, K*D)
+        v = v_ref[...].astype(jnp.float32)             # (bd, K*Dv)
+        s = jax.lax.dot_general(q, k, _NT)             # (Hp, bd)
+        slot = ki * bd + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(slot < length, s, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(p, v)
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -538,31 +523,44 @@ def flash_decode(q, k, v, lengths, *, scale: float = None,
     nk = L // bd
     if scale is None:
         scale = D ** -0.5
+    Hp = -(-H // 8) * 8
+    # block-diagonal query: head h's (pre-scaled) vector in the lanes of kv
+    # head h // rep, zeros in every other kv head's lanes and pad rows
+    own = jnp.arange(Hp)[:, None] // rep == jnp.arange(K)[None, :]
+    qh = jnp.pad(q[:, 0].astype(jnp.float32) * scale,
+                 ((0, 0), (0, Hp - H), (0, 0)))
+    qbd = jnp.where(own[None, :, :, None], qh[:, :, None, :], 0.0)
+    qbd = qbd.reshape(B, Hp, K * D)
 
-    def kv_map(b, h, ki, len_ref):
+    def kv_map(b, ki, len_ref):
         last = jnp.maximum((len_ref[b] + bd - 1) // bd - 1, 0)
-        return (b, jnp.minimum(ki, last), h // rep, 0)
+        return (b, jnp.minimum(ki, last), 0)
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=float(scale), bd=bd, nk=nk),
+        functools.partial(_decode_kernel, bd=bd, nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, nk),
+            grid=(B, nk),
             in_specs=[
-                pl.BlockSpec((1, 1, 1, D),
-                             lambda b, h, ki, len_ref: (b, 0, h, 0)),
-                pl.BlockSpec((1, bd, 1, D), kv_map),
-                pl.BlockSpec((1, bd, 1, Dv), kv_map),
+                pl.BlockSpec((None, Hp, K * D),
+                             lambda b, ki, len_ref: (b, 0, 0)),
+                pl.BlockSpec((None, bd, K * D), kv_map),
+                pl.BlockSpec((None, bd, K * Dv), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, 1, Dv),
-                                   lambda b, h, ki, len_ref: (b, 0, h, 0)),
+            out_specs=pl.BlockSpec((None, Hp, K * Dv),
+                                   lambda b, ki, len_ref: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((1, Dv), jnp.float32),
-                pltpu.VMEM((1,), jnp.float32),
-                pltpu.VMEM((1,), jnp.float32),
+                pltpu.VMEM((Hp, K * Dv), jnp.float32),
+                pltpu.VMEM((Hp, 1), jnp.float32),
+                pltpu.VMEM((Hp, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, H, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, K * Dv), jnp.float32),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k, v)
-    return out
+    )(lengths.astype(jnp.int32), qbd, k.reshape(B, L, K * D),
+      v.reshape(B, L, K * Dv))
+    # keep each head's own kv-head block of the output lanes
+    out = out[:, :H].reshape(B, H, K, Dv)
+    out = jnp.take_along_axis(
+        out, (jnp.arange(H) // rep)[None, :, None, None], axis=2)[:, :, 0]
+    return out[:, None].astype(q.dtype)

@@ -10,10 +10,11 @@ over the ``SlabView`` layout (kernels.layout):
 
   phase 1  ``stats_kernel``   — reads each gradient tile once, reduces
            per-row (sum, sum_sq, absmax, nonfinite) and segment-combines
-           them into per-LAYER accumulators in-kernel via a one-hot matmul
-           against the static per-row layer ids (subsuming grad_stats,
-           _tree_finite and global_norm: the global sq-norm is the sum of
-           the per-layer sum_sq, the finite gate is nonfinite == 0).
+           them into per-LAYER accumulators in-kernel via masked row
+           reductions against the static per-row layer ids (subsuming
+           grad_stats, _tree_finite and global_norm: the global sq-norm is
+           the sum of the per-layer sum_sq, the finite gate is
+           nonfinite == 0).
 
   (scalar combine, jnp, O(L))  — loss-scale/accum unscale, global clip
            coefficient, variance-EMA control update, curvature-scaled lr
@@ -31,10 +32,15 @@ over the ``SlabView`` layout (kernels.layout):
            delayed-scaling semantics; the reference path re-reduces a
            fresh per-tensor amax instead).
 
-Per-layer control scalars reach the kernels as per-row (1, SLAB_M) vectors
-gathered outside (footprint/SLAB_N elements — negligible), so precision
-codes, lr scales and cast scales are all runtime values: one compiled
-kernel serves every control decision with zero recompiles.
+Per-layer control scalars reach the kernels as per-row metadata gathered
+outside (footprint/SLAB_N elements — negligible): one (8, SLAB_M) f32 block
+per tile holding each row's lr, precision code, cast scale and layer id
+along lanes (``row_meta``). The kernels turn it into (SLAB_M, 8) columns
+with one transpose per tile, so precision codes, lr scales and cast scales
+are all runtime values: one compiled kernel serves every control decision
+with zero recompiles. Per-layer results come back lane-major as one
+(8, LP) block — layer l in lane l, one statistic per sublane row — reduced
+over the tile's rows with a (SLAB_M, LP) row->layer mask.
 
 Gradient-footprint traffic: 2 reads + 2 writes (master + compute copy)
 versus >= 6 reads + 4 writes on the reference path —
@@ -48,66 +54,103 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.layout import SLAB_M, SLAB_N, SlabView
 
-try:                                    # TPU-only PRNG/SR primitives
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU_SR = hasattr(pltpu, "stochastic_round")
-except ImportError:                     # pragma: no cover - no TPU plugin
-    pltpu = None
-    _HAS_PLTPU_SR = False
-
 FP8_MAX = 448.0
 
-# add-accumulated stat columns (phase-1 output)
-COL_SUM, COL_SQ, COL_NF = 0, 1, 2
+# per-layer statistic rows of the (8, LP) phase-1 output block
+ROW_SUM, ROW_SQ, ROW_NF, ROW_MAX = 0, 1, 2, 3
+# per-row metadata rows of the (8, SLAB_M) block (``row_meta``)
+META_LR, META_CODE, META_QS, META_LAYER = 0, 1, 2, 3
 
 
-def _l_pad(num_layers: int) -> int:
-    return max(8, -(-num_layers // 8) * 8)
+def _lanes(num_layers: int) -> int:
+    """Lane width of the per-layer output block (layer l in lane l)."""
+    return -(-num_layers // 128) * 128
 
 
-def _one_hot(ids, l_pad: int):
-    """(l_pad, SLAB_M) float mask from a (SLAB_M,) int32 layer-id vector."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (l_pad, SLAB_M), 0)
-    return (iota == ids[None, :]).astype(jnp.float32)
+def row_meta(row_layer, lr_rows=None, code_rows=None, qs_rows=None):
+    """(n_tiles, 8, SLAB_M) f32 per-row metadata: rows META_* hold each slab
+    row's lr, precision code, cast scale and layer id (all exact in f32);
+    the per-row inputs are (n_tiles, SLAB_M) blocks (SlabView.row_blocks /
+    gather_rows). One 8 KiB block per grid step, read lane-major."""
+    rows = [jnp.zeros(row_layer.shape, jnp.float32)] * 8
+    for r, x in ((META_LR, lr_rows), (META_CODE, code_rows),
+                 (META_QS, qs_rows), (META_LAYER, row_layer)):
+        if x is not None:
+            rows[r] = x.astype(jnp.float32).reshape(row_layer.shape)
+    return jnp.stack(rows, axis=1)
+
+
+def _meta_cols(meta_ref):
+    """The tile's (8, SLAB_M) metadata block as (SLAB_M, 8) columns."""
+    return jnp.transpose(meta_ref[...])
+
+
+def _layer_mask(cols, lanes: int):
+    """(SLAB_M, lanes) bool: row r belongs to layer l (lane l)."""
+    ids = cols[:, META_LAYER:META_LAYER + 1].astype(jnp.int32)
+    return jax.lax.broadcasted_iota(jnp.int32, (SLAB_M, lanes), 1) == ids
+
+
+def _per_layer(mask, col, reduce):
+    """Reduce a (SLAB_M, 1) per-row column into (1, lanes) per-layer values
+    (rows of other layers contribute 0: every statistic here is >= 0 or a
+    sum)."""
+    return reduce(jnp.where(mask, col, 0.0), axis=0, keepdims=True)
+
+
+def _stack_rows(vals, lanes: int):
+    """Place (1, lanes) rows 0..len(vals)-1 of an (8, lanes) block."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (8, lanes), 0)
+    out = jnp.zeros((8, lanes), jnp.float32)
+    for i, v in enumerate(vals):
+        out = jnp.where(r == i, v, out)
+    return out
+
+
+def _lane_block(lanes: int):
+    return pl.BlockSpec((8, lanes), lambda i: (0, 0))
+
+
+def _meta_block():
+    return pl.BlockSpec((None, 8, SLAB_M), lambda i: (i, 0, 0))
 
 
 # =============================================================== phase 1 ===
-def _stats_kernel(layer_ref, x_ref, add_ref, max_ref, *, l_pad: int):
+def _stats_kernel(meta_ref, x_ref, acc_ref, *, lanes: int):
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)                       # (SLAB_M, SLAB_N)
     ok = jnp.isfinite(x)
     # non-finite lanes are COUNTED (rnf drives the global skip gate) but
-    # excluded from the moments: a raw inf/nan would turn the one-hot
-    # segment matmul into 0*inf = NaN for EVERY layer and permanently
-    # poison the whole var_ema (the jnp reference merely NaNs the
-    # offending layer; the fused path keeps even that layer's EMA alive
-    # across overflow steps — the skipped step contributes its finite
-    # lanes only)
+    # excluded from the moments: a raw inf/nan would reach every layer's
+    # masked reduction as 0*inf = NaN and permanently poison the whole
+    # var_ema (the jnp reference merely NaNs the offending layer; the fused
+    # path keeps even that layer's EMA alive across overflow steps — the
+    # skipped step contributes its finite lanes only)
     xf = jnp.where(ok, x, 0.0)
     rs = jnp.sum(xf, axis=1, keepdims=True)                  # (SLAB_M, 1)
     rss = jnp.sum(jnp.square(xf), axis=1, keepdims=True)
     rnf = jnp.sum(jnp.where(ok, 0.0, 1.0), axis=1, keepdims=True)
-    rmx = jnp.max(jnp.abs(xf), axis=1)                       # (SLAB_M,)
+    rmx = jnp.max(jnp.abs(xf), axis=1, keepdims=True)
 
-    onehot = _one_hot(layer_ref[0, :], l_pad)
-    stacked = jnp.concatenate(
-        [rs, rss, rnf, jnp.zeros((SLAB_M, 128 - 3), jnp.float32)], axis=1)
-    add_up = jnp.dot(onehot, stacked, preferred_element_type=jnp.float32)
-    mx_up = jnp.max(jnp.where(onehot > 0, rmx[None, :], 0.0), axis=1)
-    mx_up = jnp.broadcast_to(mx_up[:, None], (l_pad, 128))
+    mask = _layer_mask(_meta_cols(meta_ref), lanes)
+    upd = _stack_rows([_per_layer(mask, rs, jnp.sum),
+                       _per_layer(mask, rss, jnp.sum),
+                       _per_layer(mask, rnf, jnp.sum),
+                       _per_layer(mask, rmx, jnp.max)], lanes)
 
     @pl.when(i == 0)
     def _init():
-        add_ref[...] = add_up
-        max_ref[...] = mx_up
+        acc_ref[...] = upd
 
     @pl.when(i > 0)
     def _acc():
-        add_ref[...] += add_up
-        max_ref[...] = jnp.maximum(max_ref[...], mx_up)
+        prev = acc_ref[...]
+        is_max = jax.lax.broadcasted_iota(jnp.int32, (8, lanes), 0) == ROW_MAX
+        acc_ref[...] = jnp.where(is_max, jnp.maximum(prev, upd), prev + upd)
 
 
 @functools.partial(jax.jit, static_argnames=("num_layers", "interpret"))
@@ -117,23 +160,20 @@ def fused_stats(g_slab: jax.Array, row_layer: jax.Array, num_layers: int,
 
     ``row_layer`` is the SlabView's static (n_tiles, SLAB_M) layer-id
     blocks. Returns four (num_layers,) fp32 vectors."""
-    l_pad = _l_pad(num_layers)
+    lanes = _lanes(num_layers)
     nb = g_slab.shape[0] // SLAB_M
-    add, mx = pl.pallas_call(
-        functools.partial(_stats_kernel, l_pad=l_pad),
+    acc = pl.pallas_call(
+        functools.partial(_stats_kernel, lanes=lanes),
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, SLAB_M), lambda i: (i, 0)),     # layer ids
-            pl.BlockSpec((SLAB_M, SLAB_N), lambda i: (i, 0)),
-        ],
-        out_specs=[pl.BlockSpec((l_pad, 128), lambda i: (0, 0)),
-                   pl.BlockSpec((l_pad, 128), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((l_pad, 128), jnp.float32),
-                   jax.ShapeDtypeStruct((l_pad, 128), jnp.float32)],
+        in_specs=[_meta_block(),
+                  pl.BlockSpec((SLAB_M, SLAB_N), lambda i: (i, 0))],
+        out_specs=_lane_block(lanes),
+        out_shape=jax.ShapeDtypeStruct((8, lanes), jnp.float32),
         interpret=interpret,
-    )(row_layer, g_slab)
+    )(row_meta(row_layer), g_slab)
     L = num_layers
-    return add[:L, COL_SUM], add[:L, COL_SQ], mx[:L, 0], add[:L, COL_NF]
+    return (acc[ROW_SUM, :L], acc[ROW_SQ, :L], acc[ROW_MAX, :L],
+            acc[ROW_NF, :L])
 
 
 # =============================================================== phase 2 ===
@@ -190,13 +230,12 @@ def _tier_select(cwf, code, qs, ladder: str):
     return jnp.where(code == 0, low, jnp.where(code == 1, mid, cwf))
 
 
-def _apply_kernel(scal_ref, layer_ref, lr_ref, code_ref, qs_ref,
-                  g_ref, p_ref, m_ref, v_ref,
+def _apply_kernel(scal_ref, meta_ref, g_ref, p_ref, m_ref, v_ref,
                   p_out, m_out, v_out, cp_out, pmax_ref,
-                  *, spec: OptSpec, ladder: str, l_pad: int,
+                  *, spec: OptSpec, ladder: str, lanes: int,
                   sr: bool = False, interpret: bool = False):
-    """(scalars) = [gscale, keep, c1, c2, sr_seed]; ``v_ref``/``v_out`` are
-    None for sgdm (momentum rides in ``m``)."""
+    """(scalars, in SMEM) = [gscale, keep, c1, c2, sr_seed];
+    ``v_ref``/``v_out`` are None for sgdm (momentum rides in ``m``)."""
     i = pl.program_id(0)
     gscale = scal_ref[0]
     keep = scal_ref[1] > 0.0
@@ -216,8 +255,8 @@ def _apply_kernel(scal_ref, layer_ref, lr_ref, code_ref, qs_ref,
         if spec.weight_decay:
             step = step + spec.weight_decay * p
 
-    lr = lr_ref[...].reshape(SLAB_M, 1)
-    pn = p - lr * step
+    cols = _meta_cols(meta_ref)                              # (SLAB_M, 8)
+    pn = p - cols[:, META_LR:META_LR + 1] * step
     pn = jnp.where(keep, pn, p)                              # non-finite skip
     m2 = jnp.where(keep, m2, m_ref[...])
     p_out[...] = pn
@@ -230,27 +269,25 @@ def _apply_kernel(scal_ref, layer_ref, lr_ref, code_ref, qs_ref,
         # stochastic container cast (bf16 only): kills the systematic
         # round-to-nearest EMA bias of repeated master->compute casts.
         # Tier rounding below (fp8) stays RTN — delayed scales assume it.
-        seed = scal_ref[4].astype(jnp.uint32)
-        if _HAS_PLTPU_SR and not interpret:      # pragma: no cover - TPU
+        seed = scal_ref[4].astype(jnp.int32)
+        if interpret:
+            cwf = _sr_to_bf16(pn, _sr_bits(i, seed.astype(jnp.uint32)))
+        else:
             pltpu.prng_seed(seed, i)
             bits = pltpu.bitcast(
                 pltpu.prng_random_bits((SLAB_M, SLAB_N)), jnp.uint32)
             cwf = pltpu.stochastic_round(
                 pn, bits, target_dtype=jnp.bfloat16).astype(jnp.float32)
-        else:
-            cwf = _sr_to_bf16(pn, _sr_bits(i, seed))
     else:
         cwf = pn.astype(cp_out.dtype).astype(jnp.float32)
-    code = code_ref[...].reshape(SLAB_M, 1)
-    qs = qs_ref[...].reshape(SLAB_M, 1)
-    cp_out[...] = _tier_select(cwf, code, qs, ladder).astype(cp_out.dtype)
+    cp_out[...] = _tier_select(
+        cwf, cols[:, META_CODE:META_CODE + 1],
+        cols[:, META_QS:META_QS + 1], ladder).astype(cp_out.dtype)
 
     # per-layer absmax of the fresh compute copy (next step's fp8 scales)
-    onehot = _one_hot(layer_ref[0, :], l_pad)
-    rmx = jnp.max(jnp.abs(cwf), axis=1)
+    rmx = jnp.max(jnp.abs(cwf), axis=1, keepdims=True)
     mx_up = jnp.broadcast_to(
-        jnp.max(jnp.where(onehot > 0, rmx[None, :], 0.0), axis=1)[:, None],
-        (l_pad, 128))
+        _per_layer(_layer_mask(cols, lanes), rmx, jnp.max), (8, lanes))
 
     @pl.when(i == 0)
     def _init():
@@ -275,33 +312,30 @@ def fused_apply(g_slab, p_slab, m_slab, v_slab, scalars, row_layer,
     the seed each step costs zero recompiles.
 
     Returns (p_new, m_new, v_new | None, compute_copy, p_amax(L,))."""
-    l_pad = _l_pad(num_layers)
+    lanes = _lanes(num_layers)
     nb = g_slab.shape[0] // SLAB_M
     adam = spec.kind == "adamw"
     sr = bool(sr) and jnp.dtype(cp_dtype) == jnp.dtype(jnp.bfloat16)
     if scalars.shape[0] == 4:                    # legacy (no-seed) callers
         scalars = jnp.concatenate([scalars, jnp.zeros((1,), scalars.dtype)])
 
-    def kernel(scal, layer, lr, code, qs, g, p, m, *rest):
+    def kernel(scal, meta, g, p, m, *rest):
         if adam:
             v, p_o, m_o, v_o, cp_o, pmax = rest
         else:
             p_o, m_o, cp_o, pmax = rest
             v, v_o = None, None
-        _apply_kernel(scal, layer, lr, code, qs, g, p, m, v,
-                      p_o, m_o, v_o, cp_o, pmax,
-                      spec=spec, ladder=ladder, l_pad=l_pad,
+        _apply_kernel(scal, meta, g, p, m, v, p_o, m_o, v_o, cp_o, pmax,
+                      spec=spec, ladder=ladder, lanes=lanes,
                       sr=sr, interpret=interpret)
 
-    row_spec = pl.BlockSpec((1, SLAB_M), lambda i: (i, 0))
     slab_spec = pl.BlockSpec((SLAB_M, SLAB_N), lambda i: (i, 0))
-    acc_spec = pl.BlockSpec((l_pad, 128), lambda i: (0, 0))
     slab_sds = jax.ShapeDtypeStruct(p_slab.shape, jnp.float32)
 
-    in_specs = [pl.BlockSpec((5,), lambda i: (0,)),          # scalars
-                row_spec, row_spec, row_spec, row_spec,
-                slab_spec, slab_spec, slab_spec]
-    args = [scalars, row_layer, lr_rows, code_rows, qs_rows,
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),       # scalars
+                _meta_block(), slab_spec, slab_spec, slab_spec]
+    args = [scalars.astype(jnp.float32),
+            row_meta(row_layer, lr_rows, code_rows, qs_rows),
             g_slab, p_slab, m_slab]
     out_specs = [slab_spec, slab_spec]
     out_shape = [slab_sds, slab_sds]
@@ -310,9 +344,9 @@ def fused_apply(g_slab, p_slab, m_slab, v_slab, scalars, row_layer,
         args.append(v_slab)
         out_specs.append(slab_spec)
         out_shape.append(slab_sds)
-    out_specs += [slab_spec, acc_spec]
+    out_specs += [slab_spec, _lane_block(lanes)]
     out_shape += [jax.ShapeDtypeStruct(p_slab.shape, cp_dtype),
-                  jax.ShapeDtypeStruct((l_pad, 128), jnp.float32)]
+                  jax.ShapeDtypeStruct((8, lanes), jnp.float32)]
 
     outs = pl.pallas_call(
         kernel, grid=(nb,), in_specs=in_specs, out_specs=out_specs,
@@ -322,7 +356,7 @@ def fused_apply(g_slab, p_slab, m_slab, v_slab, scalars, row_layer,
         p_new, m_new, v_new, cp, pmax = outs
     else:
         (p_new, m_new, cp, pmax), v_new = outs, None
-    return p_new, m_new, v_new, cp, pmax[:num_layers, 0]
+    return p_new, m_new, v_new, cp, pmax[0, :num_layers]
 
 
 # ===================================================== jnp-side helpers ===

@@ -6,13 +6,15 @@ validated everywhere while the BlockSpec tiling targets TPU.
 
 ``flash_attention`` here is a DIFFERENTIABLE op: the kernel path is bound
 to the Pallas backward kernels with ``jax.custom_vjp`` (forward emits the
-logsumexp residual; backward runs the dO·O preprocess, dQ, and dK/dV
-kernels), and the dispatch gate guards the whole differentiable op — a
-configuration the kernel cannot handle falls back to the chunked/naive jnp
-paths, which JAX differentiates natively. One asymmetry of custom_vjp:
-forward-mode AD (jax.jvp, used by the §3.2 curvature HVPs) cannot pass
-through it — trace-time callers that need jvp wrap themselves in
-``flash_fallback()`` (repro.train.task.curvature_loss does), which pins
+logsumexp residual; backward reduces the dO·O row sums in XLA, then runs
+the dQ and dK/dV kernels), and the dispatch gate guards the whole
+differentiable op — a configuration the kernel cannot handle falls back to
+the chunked/naive jnp paths, which JAX differentiates natively. On a
+multi-device activation mesh the kernels run per shard under
+``shard_map`` (GSPMD does not partition Mosaic kernels). One asymmetry of
+custom_vjp: forward-mode AD (jax.jvp, used by the §3.2 curvature HVPs)
+cannot pass through it — trace-time callers that need jvp wrap themselves
+in ``flash_fallback()`` (repro.train.task.curvature_loss does), which pins
 dispatch to the jnp paths.
 """
 from __future__ import annotations
@@ -166,6 +168,28 @@ def _note_fallback(reason: str) -> None:
             "chunked/naive jnp fallback", stacklevel=3)
 
 
+def _per_shard(fn, q, k, v, *segments):
+    """Run a Pallas attention op on each device's shard of the activation
+    mesh (``launch.sharding.activation_mesh``): GSPMD does not partition
+    Mosaic kernels, so ``shard_map`` hands every device its batch rows (and
+    its heads, when the "model" axis divides both head counts)."""
+    from repro.launch.sharding import current_mesh, fsdp_axes
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v, *segments)
+    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
+    dp = fsdp_axes(mesh)
+    rows = (dp if len(dp) > 1 else dp[0]) if dp else None
+    m = mesh.shape.get("model", 1)
+    heads = ("model" if m > 1 and q.shape[2] % m == 0
+             and k.shape[2] % m == 0 else None)
+    spec = P(rows, None, heads, None)
+    return shard_map(fn, mesh=mesh,
+                     in_specs=(spec,) * 3 + (P(rows, None),) * len(segments),
+                     out_specs=spec, check_rep=False)(q, k, v, *segments)
+
+
 # ----------------------------------------------- differentiable kernel op --
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_diff(q, k, v, causal, window, scale, interpret):
@@ -241,11 +265,13 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, segments=None,
     reason = kernel_fallback_reason(q.shape, k.shape, v.shape, q_pos, k_pos,
                                     window, segments)
     if not forced and not reason:
+        static = (bool(causal), win, float(scale), _interpret())
         if segments is not None:
-            return _flash_diff_seg(q, k, v, segments, bool(causal), win,
-                                   float(scale), _interpret())
-        return _flash_diff(q, k, v, bool(causal), win, float(scale),
-                           _interpret())
+            return _per_shard(
+                lambda q, k, v, s: _flash_diff_seg(q, k, v, s, *static),
+                q, k, v, segments)
+        return _per_shard(lambda q, k, v: _flash_diff(q, k, v, *static),
+                          q, k, v)
     if not forced:
         _note_fallback(reason)
     from repro.nn.attention import _chunked_attention, _naive_attention

@@ -99,7 +99,7 @@ def qdq_cast(x: jax.Array, code: jax.Array, ladder: str = "tpu",
             functools.partial(_qdq_fused_kernel, ladder=ladder),
             grid=(2, nb),
             in_specs=[
-                pl.BlockSpec((1,), lambda p, i: (0,)),           # code
+                pl.BlockSpec(memory_space=pltpu.SMEM),           # code
                 pl.BlockSpec((BLOCK_M, BLOCK_N), lambda p, i: (i, 0)),
             ],
             out_specs=pl.BlockSpec((BLOCK_M, BLOCK_N), lambda p, i: (i, 0)),
@@ -117,8 +117,8 @@ def qdq_cast(x: jax.Array, code: jax.Array, ladder: str = "tpu",
             functools.partial(_qdq_kernel, ladder=ladder),
             grid=(nb,),
             in_specs=[
-                pl.BlockSpec((1,), lambda i: (0,)),              # code
-                pl.BlockSpec((1,), lambda i: (0,)),              # scale
+                pl.BlockSpec(memory_space=pltpu.SMEM),           # code
+                pl.BlockSpec(memory_space=pltpu.SMEM),           # scale
                 pl.BlockSpec((BLOCK_M, BLOCK_N), lambda i: (i, 0)),
             ],
             out_specs=pl.BlockSpec((BLOCK_M, BLOCK_N), lambda i: (i, 0)),

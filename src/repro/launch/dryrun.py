@@ -259,8 +259,9 @@ def run_cell(arch, shape_name, mesh_kind, hw=HW(), out_dir=None,
                  else (256 // n, n))
         axes = (("pod", "data", "model") if mesh_kind == "multi"
                 else ("data", "model"))
-        from repro.launch.mesh import _axis_types_kw
-        mesh = jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+        mesh = jax.make_mesh(
+            shape, axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     chips = mesh.size

@@ -3,12 +3,8 @@ this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-
-def _axis_types_kw(n: int) -> dict:
-    # jax < 0.5 has no sharding.AxisType (everything is Auto implicitly)
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,10 +14,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     import so these meshes exist on CPU."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_dev_mesh():
-    """1x1 mesh with production axis names — tests/examples run the exact
-    same pjit code path on a single device."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_types_kw(2))
+def make_dev_mesh(devices=None) -> Mesh:
+    """(data, model) = (n, 1) mesh with production axis names over
+    ``devices`` (default: every device of the process): every device given
+    is used along "data", never a silently smaller mesh."""
+    devices = list(jax.devices() if devices is None else devices)
+    if not devices:
+        raise ValueError("make_dev_mesh needs at least one device")
+    grid = np.asarray(devices, dtype=object).reshape(-1, 1)
+    return Mesh(grid, ("data", "model"), axis_types=(AxisType.Auto,) * 2)

@@ -89,10 +89,15 @@ def activation_mesh(mesh: Optional[Mesh]):
         _ACT.mesh = prev
 
 
+def current_mesh() -> Optional[Mesh]:
+    """The mesh installed by ``activation_mesh`` (None outside one)."""
+    return getattr(_ACT, "mesh", None)
+
+
 def constrain(x, dims: Tuple[Optional[str], ...]):
     """dims entries: "batch" (fsdp axes), "model", or None. Skips any dim the
     mesh doesn't divide; no-op outside an activation_mesh context."""
-    mesh = getattr(_ACT, "mesh", None)
+    mesh = current_mesh()
     if mesh is None or x.ndim != len(dims):
         return x
     spec = []

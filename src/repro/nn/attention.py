@@ -10,7 +10,7 @@ Three execution paths:
     configs; validated in interpret mode in tests). Differentiable
     end-to-end: kernels.ops binds the Pallas backward kernels with
     jax.custom_vjp, so training runs the kernel in BOTH directions with
-    only the (B, H, S) logsumexp residual saved — no O(S*S/chunk)
+    only the (B, H, 1, S) logsumexp residual saved — no O(S*S/chunk)
     score residuals. Packed multi-document batches run the kernel too:
     ``segments`` (per-row non-decreasing int32 document ids) feed the
     kernels' segment block masking when the constructor declares the

@@ -47,8 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.batch_scaler import BatchScaler
-from repro.core.precision import TriAccelConfig
+from repro.core.batch_scaler import BatchScaler, with_device_cap
+from repro.core.precision import TriAccelConfig, check_ladder_kernels
 from repro.nn.module import split_params
 from repro.serve.batching import Request, RequestQueue, pick_rung
 from repro.serve.engine import ServeEngine
@@ -71,7 +71,7 @@ class ServeConfig:
     cache_dtype: Any = jnp.bfloat16
     max_new_tokens: int = 16          # per-request default
     t_ctrl: int = 8                   # §3.4 control cadence, in decode steps
-    mem_cap_bytes: float = 16e9
+    mem_cap_bytes: Optional[float] = None   # None: the device's own limit
     auto_tier: bool = True
     seed: int = 0
     # --- SLO scheduling (DESIGN.md §11) ---------------------------------
@@ -105,10 +105,13 @@ class ServeSession:
         if params is None:
             wrapped, aux_state = self.task.init(jax.random.PRNGKey(cfg.seed))
             params, _ = split_params(wrapped)
-        self.tac = tac if tac is not None else TriAccelConfig(
+        device = jax.devices()[0]
+        self.tac = with_device_cap(tac if tac is not None else TriAccelConfig(
             ladder=cfg.ladder, mem_cap_bytes=cfg.mem_cap_bytes,
-            t_ctrl=cfg.t_ctrl)
+            t_ctrl=cfg.t_ctrl), device)
         tiers = tuple(sorted(set(cfg.tiers)))
+        if 0 in tiers:                     # tier 0 runs the qdq_cast kernel
+            check_ladder_kernels(cfg.ladder, device.platform)
         self.tier = 1 if 1 in tiers else tiers[-1]
         self._tier_locked = not cfg.auto_tier
         self.mm = self.task.serve_memory_model(

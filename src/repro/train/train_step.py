@@ -306,10 +306,11 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
             loss_fn, has_aux=True)(wrt, aux_state, batch, *extra)
         return grads, new_aux, metrics
 
-    def _control_metrics(metrics, finite, control2, lr):
+    def _control_metrics(metrics, finite, control2, lr, gn):
         metrics = dict(metrics)
         metrics.update({
             "grads_finite": finite,
+            "grad_norm": gn,                # global norm before clipping
             "loss_scale": control2.loss_scale,
             "lr": lr,
             "mean_code": jnp.mean(control2.codes.astype(jnp.float32)),
@@ -330,8 +331,8 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
 
         grads = jax.tree.map(lambda g: (g.astype(jnp.float32) / ls), grads)
         finite = _tree_finite(grads)
+        gn = global_norm(grads)
         if grad_clip > 0:
-            gn = global_norm(grads)
             clip = jnp.minimum(1.0, grad_clip / jnp.maximum(gn, 1e-9))
             grads = jax.tree.map(lambda g: g * clip, grads)
 
@@ -353,7 +354,7 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
         opt_state2 = keep(opt_state2, opt_state)
         new_aux = keep(new_aux, aux_state)
 
-        metrics = _control_metrics(metrics, finite, control2, lr)
+        metrics = _control_metrics(metrics, finite, control2, lr, gn)
         return TrainState(new_params, new_aux, opt_state2, control2,
                           state.compute), metrics
 
@@ -383,8 +384,8 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
         s_l = sums / denom
         ss_l = sumsqs / jnp.square(denom)
         finite = jnp.sum(nonfinite) == 0
+        gn = jnp.sqrt(jnp.sum(ss_l))
         if grad_clip > 0:
-            gn = jnp.sqrt(jnp.sum(ss_l))
             clip = jnp.minimum(1.0, grad_clip / jnp.maximum(gn, 1e-9))
         else:
             clip = jnp.float32(1.0)
@@ -432,7 +433,7 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
         compute2 = {"tree": view.unpack(cp_slab, like=params32),
                     "p_amax": p_amax}
 
-        metrics = _control_metrics(metrics, finite, control2, lr)
+        metrics = _control_metrics(metrics, finite, control2, lr, gn)
         # phase-1 absmax of the UNSCALED finite gradient lanes: the fp16
         # ladder's overflow-margin diagnostic (free — the stats sweep
         # already reduced it)
@@ -526,8 +527,8 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
         s_l = sums / denom
         ss_l = sumsqs / jnp.square(denom)
         finite = jnp.sum(nonfinite) == 0
+        gn = jnp.sqrt(jnp.sum(ss_l))
         if grad_clip > 0:
-            gn = jnp.sqrt(jnp.sum(ss_l))
             clip = jnp.minimum(1.0, grad_clip / jnp.maximum(gn, 1e-9))
         else:
             clip = jnp.float32(1.0)
@@ -568,7 +569,7 @@ def make_train_step(task, tac: TriAccelConfig, opt: Optimizer,
                                new_aux, aux_state)
         compute2 = {"slab": cp_slab, "p_amax": p_amax}
 
-        metrics = _control_metrics(metrics, finite, control2, lr)
+        metrics = _control_metrics(metrics, finite, control2, lr, gn)
         metrics["grad_absmax"] = jnp.max(gmax) / denom
         return TrainState(p_new, new_aux, opt_state2, control2,
                           compute2), metrics

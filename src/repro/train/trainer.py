@@ -28,9 +28,9 @@ import numpy as np
 from repro.checkpoint.checkpoint import (AsyncCheckpointer, latest_step,
                                          manifest_keys, restore_checkpoint)
 from repro.core import curvature as curv
-from repro.core.batch_scaler import BatchScaler
+from repro.core.batch_scaler import BatchScaler, with_device_cap
 from repro.core.controller import init_control, with_curvature
-from repro.core.precision import TriAccelConfig
+from repro.core.precision import TriAccelConfig, check_ladder_kernels
 from repro.launch.mesh import make_dev_mesh
 from repro.launch import sharding as shd
 from repro.nn.module import split_params
@@ -85,9 +85,10 @@ class Trainer:
             task = task_for_config(task)
         self.task = task
         self.cfg = task.cfg
-        self.tac = tac
         self.tcfg = tcfg
         self.mesh = mesh if mesh is not None else make_dev_mesh()
+        device = self.mesh.devices.flat[0]
+        self.tac = tac = with_device_cap(tac, device)
         key = jax.random.PRNGKey(tcfg.seed)
 
         wrapped, aux_state = task.init(key)
@@ -107,6 +108,8 @@ class Trainer:
                                  tcfg.total_steps)
         self.fused = (tcfg.fused_update if tcfg.fused_update is not None
                       else resolve_fused(opt, tac))
+        if self.fused:
+            check_ladder_kernels(tac.ladder, device.platform)
         # slab residency (DESIGN.md §10): master/moments/compute live as
         # (rows, 512) slabs ACROSS steps whenever the step is fused — pack
         # runs once here (and on restore), unpack only at checkpoint/eval/
@@ -296,7 +299,12 @@ class Trainer:
         stream = dataclasses.replace(
             self.stream, global_batch=self._dp_size() * rung) \
             if self.tcfg.elastic_true_batch else self.stream
-        return stream.batch(step)
+        return self._place_batch(stream.batch(step))
+
+    def _place_batch(self, batch):
+        """Lay a host-built global batch over the mesh's data axes (the
+        step executables are compiled for exactly this placement)."""
+        return jax.device_put(batch, shd.batch_shardings(batch, self.mesh))
 
     # ------------------------------------------------- fault tolerance ----
     def install_preemption_handler(self):
@@ -568,7 +576,7 @@ class Trainer:
         corrupt_checkpoint(self.tcfg.ckpt_dir, f.kind, self.fault_plan.rng)
 
     def _curvature(self, step: int):
-        mb = self.stream.batch(step)
+        mb = self._place_batch(self.stream.batch(step))
         small = jax.tree.map(lambda x: x[:self.tcfg.b_curv], mb)
         aux = self.state.aux_state
         params = self.params_tree()          # eval boundary: one unpack

@@ -167,6 +167,15 @@ def test_r5_seeded_nondividing_block_fires():
                for sev, _, msg in found), found
 
 
+def test_r5_seeded_untiled_block_fires():
+    """A (4, 512) row block over a (64, 512) array divides it but breaks the
+    TPU (8, 128) rule on the second-minor dim — Mosaic refuses it."""
+    jx = _pl_jaxpr((16,), (4, 512), (64, 512), (64, 512))
+    found = pallas_findings(jx)
+    assert any(sev == "error" and "(8, 128)" in msg
+               for sev, _, msg in found), found
+
+
 def test_r5_seeded_grid_undercoverage_fires():
     jx = _pl_jaxpr((1,), (256, 512), (512, 512), (512, 512))
     found = pallas_findings(jx)
@@ -183,12 +192,13 @@ def test_r5_wellformed_tiling_is_clean():
 
 def test_r5_covers_new_attention_variant_paths():
     """The segment/MLA/ragged kernel traces are registered hot paths, R5
-    walks ALL their pallas_calls (fwd + the three backward kernels for the
-    attention variants), and the production geometry lints clean."""
+    walks ALL their pallas_calls (fwd + the dQ and dK/dV backward kernels
+    for the attention variants), and the production geometry lints
+    clean."""
     from repro.analysis import hotpaths
     by_name = {p.name: p for p in hotpaths.kernel_paths()}
-    for name, ncalls in (("kernel/flash_attention_packed", 4),
-                         ("kernel/flash_attention_mla", 4),
+    for name, ncalls in (("kernel/flash_attention_packed", 3),
+                         ("kernel/flash_attention_mla", 3),
                          ("kernel/flash_decode_ragged", 1)):
         assert name in by_name, sorted(by_name)
         p = by_name[name]

@@ -1,5 +1,6 @@
 """Sharding rules: divisibility fallbacks, mesh-axis conflicts, cache
-heuristics, collective parser — all on a 1-device mesh + synthetic HLO."""
+heuristics, collective parser on a 1-device mesh + synthetic HLO, and the
+trainer on two virtual devices (subprocess)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,3 +97,65 @@ ENTRY %main (a: f32[64]) -> f32[64] {
 
 def test_shape_bytes_tuple():
     assert _shape_bytes("(f32[2,2], bf16[4])") == 16 + 8
+
+
+def test_dev_mesh_uses_every_device_given():
+    devs = jax.devices()
+    mesh = make_dev_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.devices.size == len(devs) and mesh.shape["model"] == 1
+    assert make_dev_mesh(devs[:1]).devices.size == 1
+    with pytest.raises(ValueError, match="device"):
+        make_dev_mesh([])
+
+
+def test_trainer_on_two_devices_matches_one():
+    """Trainer on a (2, 1) mesh — batches placed over "data", flash kernels
+    per shard under shard_map, slabs row-sharded — tracks the one-device
+    trainer on the same global batch. Subprocess: the device count must
+    be forced before jax initializes."""
+    import os
+    import re
+    import subprocess
+    import sys
+    import textwrap
+    script = textwrap.dedent("""
+        import dataclasses
+        import jax, numpy as np
+        assert jax.device_count() == 2
+        from repro.configs import smollm_135m
+        from repro.core.precision import TriAccelConfig
+        from repro.launch.mesh import make_dev_mesh
+        from repro.train.task import LMTask
+        from repro.train.trainer import Trainer, TrainerConfig
+        cfg = smollm_135m.reduced_config()
+        st = cfg.stack
+        cfg = dataclasses.replace(cfg, stack=dataclasses.replace(
+            st, attn=dataclasses.replace(st.attn, impl="flash")))
+        losses = []
+        for devs, mb in ((jax.devices(), 1), (jax.devices()[:1], 2)):
+            tac = TriAccelConfig(ladder="tpu", t_ctrl=2,
+                                 enable_curvature=False, enable_batch=False)
+            tcfg = TrainerConfig(seq_len=256, rungs=(mb,), log_every=1,
+                                 warmup_steps=1)
+            tr = Trainer(LMTask(cfg), tac, tcfg, mesh=make_dev_mesh(devs))
+            assert tr.slab_shards == len(devs)
+            tr.warm_rungs()
+            tr.run(3)
+            assert tr.compile_count == 1
+            losses.append([m["loss"] for m in tr.metrics_log])
+        np.testing.assert_allclose(losses[0], losses[1], rtol=1e-4)
+        print("TWO_DEVICE_TRAINER_OK")
+    """)
+    inherited = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
+                       os.environ.get("XLA_FLAGS", ""))
+    env = dict(os.environ,
+               XLA_FLAGS=inherited
+               + " --xla_force_host_platform_device_count=2",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "TWO_DEVICE_TRAINER_OK" in out.stdout

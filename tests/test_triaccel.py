@@ -217,3 +217,45 @@ def test_loss_scale_halves_on_overflow():
     assert float(bad.loss_scale) == float(ctl.loss_scale) / 2
     good = update_control(ctl, mom, tac, jnp.asarray(True))
     assert float(good.loss_scale) == float(ctl.loss_scale)
+
+
+# ---------------------------------------------------- device facts --------
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "x", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_mem_cap_comes_from_the_device():
+    from repro.core.batch_scaler import (CPU_MEM_CAP, device_mem_cap,
+                                         with_device_cap)
+    tpu = _FakeDevice("tpu", {"bytes_limit": 15.75e9, "bytes_in_use": 1})
+    assert device_mem_cap(tpu) == 15.75e9
+    assert with_device_cap(TriAccelConfig(), tpu).mem_cap_bytes == 15.75e9
+    # an explicit cap wins; the CPU backend reports no limit
+    assert with_device_cap(TriAccelConfig(mem_cap_bytes=4e9),
+                           tpu).mem_cap_bytes == 4e9
+    assert device_mem_cap(_FakeDevice("cpu", None)) == CPU_MEM_CAP
+    # an accelerator that reports nothing is an error, not 16 GB
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        device_mem_cap(_FakeDevice("tpu", {}))
+
+
+def test_gpu_ladder_refused_for_tpu_kernels():
+    from repro.core.precision import check_ladder_kernels
+    with pytest.raises(ValueError, match="fp16"):
+        check_ladder_kernels("gpu", "tpu")
+    check_ladder_kernels("tpu", "tpu")
+    check_ladder_kernels("gpu", "cpu")
+
+
+def test_fp8_qdq_is_straight_through():
+    """The low-tier QDQ's gradient is the identity: differentiating the
+    e4m3 casts would round the cotangent onto the unscaled fp8 grid (NaN
+    above 448) and skip every low-tier step on the reference path."""
+    from repro.core.precision import qdq
+    x = jnp.linspace(-3.0, 3.0, 64)
+    g = jax.grad(lambda x: jnp.sum(qdq(x, 0, "tpu") * 1000.0))(x)
+    np.testing.assert_array_equal(np.asarray(g), np.full(64, 1000.0))
