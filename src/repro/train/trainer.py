@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.checkpoint import (AsyncCheckpointer, latest_step,
                                          manifest_keys, restore_checkpoint)
 from repro.core import curvature as curv
@@ -81,6 +82,11 @@ class Trainer:
 
     def __init__(self, task, tac: TriAccelConfig, tcfg: TrainerConfig,
                  mesh=None, fault_plan: Optional[FaultPlan] = None):
+        with obs.span("train.init"):
+            self._init(task, tac, tcfg, mesh, fault_plan)
+
+    def _init(self, task, tac, tcfg, mesh, fault_plan):
+        """Init, placement, slab pack, memory model and data stream."""
         if not isinstance(task, TrainTask):
             task = task_for_config(task)
         self.task = task
@@ -238,15 +244,21 @@ class Trainer:
         key = (rung, jax.tree_util.tree_structure(self.state))
         exe = self._executables.get(key)
         if exe is None:
-            state_sds = jax.tree.map(self._abstract, self.state)
-            batch_sds = jax.tree.map(self._abstract,
-                                     self._batch_for_rung(rung, 0))
-            with self.mesh, shd.activation_mesh(self.mesh):
-                exe = (jax.jit(self._step_fn, donate_argnums=(0,))
-                       .lower(state_sds, batch_sds).compile())
-            self._executables[key] = exe
-            self.compile_count += 1
-            self._harvest_measured(key, exe)
+            with obs.span("train.compile", rung=rung) as sp:
+                try:
+                    state_sds = jax.tree.map(self._abstract, self.state)
+                    batch_sds = jax.tree.map(self._abstract,
+                                             self._batch_for_rung(rung, 0))
+                    with self.mesh, shd.activation_mesh(self.mesh):
+                        exe = (jax.jit(self._step_fn, donate_argnums=(0,))
+                               .lower(state_sds, batch_sds).compile())
+                except Exception as e:      # noqa: BLE001 — re-raised
+                    if is_oom_error(e):
+                        sp.outcome = "oom"
+                    raise
+                self._executables[key] = exe
+                self.compile_count += 1
+                self._harvest_measured(key, exe)
         return exe
 
     def _harvest_measured(self, key, exe):
@@ -296,10 +308,11 @@ class Trainer:
             self._get_step(r)
 
     def _batch_for_rung(self, rung: int, step: int):
-        stream = dataclasses.replace(
-            self.stream, global_batch=self._dp_size() * rung) \
-            if self.tcfg.elastic_true_batch else self.stream
-        return self._place_batch(stream.batch(step))
+        with obs.span("train.data", rung=rung, step=step):
+            stream = dataclasses.replace(
+                self.stream, global_batch=self._dp_size() * rung) \
+                if self.tcfg.elastic_true_batch else self.stream
+            return self._place_batch(stream.batch(step))
 
     def _place_batch(self, batch):
         """Lay a host-built global batch over the mesh's data axes (the
@@ -402,61 +415,70 @@ class Trainer:
         steps = steps if steps is not None else self.tcfg.total_steps
         start = int(self.state.control.step)
         end = start + steps
-        t0 = time.time()
-        step = start
-        while step < end:
-            if self.fault_plan is not None and \
-                    self.fault_plan.fires("train.sigterm", step):
-                self._deliver_sigterm()
-            if self._preempted:
-                if self.ckpt:
-                    self.ckpt.save(step, self._save_state(), block=True)
-                    self._maybe_corrupt(step)
-                raise SystemExit(143)
-            if self.fault_plan is not None:
-                self._inject_nonfinite(step)
-            self.state, metrics, rung = self._dispatch(step)
+        with obs.span("train.run", start=start, steps=steps):
+            t0 = time.time()
+            step = start
+            while step < end:
+                with obs.step_span("train.step", step,
+                                   rung=self.scaler.microbatch):
+                    step = self._run_step(step, t0)
+            if self.ckpt:
+                self.ckpt.save(end, self._save_state(), block=True)
+                self._maybe_corrupt(end)
+        return self.metrics_log
 
-            # §3.2 curvature cadence (host side, tiny batch)
-            if self.tac.enable_curvature and step > 0 and \
-                    step % self.tac.t_curv == 0:
-                lam = self._curvature(step)
-                self.state = self.state._replace(
-                    control=with_curvature(self.state.control, lam))
-            # §3.3 batch-rung cadence: measured-first (the harvested
-            # memory_analysis() bytes of THIS rung's executable), analytic
-            # fallback when the backend reported nothing
-            if step > 0 and step % self.tac.t_ctrl == 0:
+    def _run_step(self, step: int, t0: float) -> int:
+        """One iteration of ``run``: the step and the host cadences due
+        after it. Returns the next step (a rollback's restored step)."""
+        if self.fault_plan is not None and \
+                self.fault_plan.fires("train.sigterm", step):
+            self._deliver_sigterm()
+        if self._preempted:
+            if self.ckpt:
+                self.ckpt.save(step, self._save_state(), block=True)
+                self._maybe_corrupt(step)
+            raise SystemExit(143)
+        if self.fault_plan is not None:
+            self._inject_nonfinite(step)
+        self.state, metrics, rung = self._dispatch(step)
+
+        # §3.2 curvature cadence (host side, tiny batch)
+        if self.tac.enable_curvature and step > 0 and \
+                step % self.tac.t_curv == 0:
+            lam = self._curvature(step)
+            self.state = self.state._replace(
+                control=with_curvature(self.state.control, lam))
+        # §3.3 batch-rung cadence: measured-first (the harvested
+        # memory_analysis() bytes of THIS rung's executable), analytic
+        # fallback when the backend reported nothing
+        if step > 0 and step % self.tac.t_ctrl == 0:
+            with obs.span("train.control", step=step, rung=rung) as sp:
                 codes = jax.device_get(self.state.control.codes)
                 self.scaler.observe(step, codes=list(codes),
                                     measured_bytes=self._rung_measured(rung))
-            # checkpoint cadence — suppressed while the watchdog has
-            # suspect steps in flight: a mid-burst state (control carries
-            # the overflow) must never displace the clean generation a
-            # rollback needs
-            if self.ckpt and step > 0 and step % self.tcfg.ckpt_every == 0 \
-                    and (self._watchdog is None or self._watchdog.healthy):
-                self.ckpt.save(step, self._save_state())
-                self._maybe_corrupt(step)
-            if step % self.tcfg.log_every == 0:
-                m = {k: float(v) for k, v in jax.device_get(metrics).items()}
-                m.update(step=step, rung=rung,
-                         mem_gb=self.scaler._mem(self.scaler.idx) / 1e9,
-                         wall_s=round(time.time() - t0, 2))
-                self.metrics_log.append(m)
-            if self._watchdog is not None:
-                host = jax.device_get({"loss": metrics.get("loss", 0.0),
-                                       "finite": metrics.get("grads_finite",
-                                                             True)})
-                if self._watchdog.observe(float(host["loss"]),
-                                          bool(host["finite"])):
-                    step = self._rollback(step)
-                    continue
-            step += 1
-        if self.ckpt:
-            self.ckpt.save(end, self._save_state(), block=True)
-            self._maybe_corrupt(end)
-        return self.metrics_log
+                sp.attrs["rung_after"] = self.scaler.microbatch
+        # checkpoint cadence — suppressed while the watchdog has
+        # suspect steps in flight: a mid-burst state (control carries
+        # the overflow) must never displace the clean generation a
+        # rollback needs
+        if self.ckpt and step > 0 and step % self.tcfg.ckpt_every == 0 \
+                and (self._watchdog is None or self._watchdog.healthy):
+            self.ckpt.save(step, self._save_state())
+            self._maybe_corrupt(step)
+        if step % self.tcfg.log_every == 0:
+            m = {k: float(v) for k, v in jax.device_get(metrics).items()}
+            m.update(step=step, rung=rung,
+                     mem_gb=self.scaler._mem(self.scaler.idx) / 1e9,
+                     wall_s=round(time.time() - t0, 2))
+            self.metrics_log.append(m)
+        if self._watchdog is not None:
+            host = jax.device_get({"loss": metrics.get("loss", 0.0),
+                                   "finite": metrics.get("grads_finite",
+                                                         True)})
+            if self._watchdog.observe(float(host["loss"]),
+                                      bool(host["finite"])):
+                return self._rollback(step)
+        return step + 1
 
     # ------------------------------------------- recovery (DESIGN.md §13) -
     def _dispatch(self, step: int):
@@ -576,15 +598,16 @@ class Trainer:
         corrupt_checkpoint(self.tcfg.ckpt_dir, f.kind, self.fault_plan.rng)
 
     def _curvature(self, step: int):
-        mb = self._place_batch(self.stream.batch(step))
-        small = jax.tree.map(lambda x: x[:self.tcfg.b_curv], mb)
-        aux = self.state.aux_state
-        params = self.params_tree()          # eval boundary: one unpack
-        loss_fn = lambda p, b: self.task.curvature_loss(p, aux, b)
-        if self.tac.curvature_method == "fisher":
-            g = jax.grad(loss_fn)(params, small)
-            return curv.fisher_layer(g, self.grouping.mean)
-        key = jax.random.PRNGKey(step)
-        return curv.hutchinson_layer_traces(
-            loss_fn, params, lambda t: self.grouping.mean(t),
-            key, 1, small)
+        with obs.span("train.curvature", step=step):
+            mb = self._place_batch(self.stream.batch(step))
+            small = jax.tree.map(lambda x: x[:self.tcfg.b_curv], mb)
+            aux = self.state.aux_state
+            params = self.params_tree()          # eval boundary: one unpack
+            loss_fn = lambda p, b: self.task.curvature_loss(p, aux, b)
+            if self.tac.curvature_method == "fisher":
+                g = jax.grad(loss_fn)(params, small)
+                return curv.fisher_layer(g, self.grouping.mean)
+            key = jax.random.PRNGKey(step)
+            return curv.hutchinson_layer_traces(
+                loss_fn, params, lambda t: self.grouping.mean(t),
+                key, 1, small)
