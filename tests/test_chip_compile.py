@@ -5,7 +5,8 @@ to VMEM, casts Mosaic cannot lower). Shapes are smollm-135m's: head dim 64,
 9 query / 3 kv heads, seq 2048, and its (rows, 512) update slab; flash
 training is also compiled at recurrentgemma-2b's head dim 256 (10 heads,
 1 kv head, window 2048) and deepseek-v2-lite's split MLA dims (16 heads,
-qk 192, v 128).
+qk 192, v 128), and the forward at the 32k-token prefill, whose tile table
+is the largest any configuration puts in SMEM.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every xdist worker imports this
@@ -92,6 +93,14 @@ def _flash_train_mla():
     return _flash_train(16, 16, 192, 128)
 
 
+def _flash_prefill_32k():
+    """The longest sequence any configuration runs the kernels at
+    (prefill_32k): the causal tile table, 8,256 words, must fit in SMEM."""
+    q = ((1, 32768, H, D), jnp.bfloat16)
+    kv = ((1, 32768, K, D), jnp.bfloat16)
+    return lambda q, k, v: fa.flash_attention(q, k, v), [q, kv, kv]
+
+
 def _flash_decode():
     kv = ((DECODE_B, CACHE_L, K, D), jnp.bfloat16)
     return (lambda q, k, v, n: fa.flash_decode(q, k, v, n),
@@ -128,7 +137,8 @@ def _qdq_cast():
 
 
 @pytest.mark.parametrize("build", [_flash_fwd, _flash_bwd, _flash_train_hd256,
-                                   _flash_train_mla, _flash_decode,
+                                   _flash_train_mla, _flash_prefill_32k,
+                                   _flash_decode,
                                    _fused_stats, _fused_apply, _qdq_cast],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(one_chip, build):

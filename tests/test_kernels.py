@@ -389,3 +389,128 @@ def test_flash_window_numpy_int_on_fallback_path():
     got = ops.flash_attention(q, k, v, causal=True, window=np.int64(8))
     want = _naive_attention(q, k, v, pos, pos, True, 8, D ** -0.5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+
+
+# ------------------------------------------------------ flash tile tables --
+@pytest.mark.parametrize("rep", [0, 3], ids=["fwd_dq", "dkv"])
+@pytest.mark.parametrize("window", [0, 100, 300, 700])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("nk", [1, 2, 4, 8])
+@pytest.mark.parametrize("nq", [1, 2, 4, 8])
+def test_flash_tile_table(nq, nk, causal, window, rep):
+    """The table lists exactly the tiles ``_block_needed`` keeps, in the
+    dense walk's order; each output block (the q-block, or the k-block of
+    the dK/dV walk) appears, as one run flagged first at its start and last
+    at its end. A block with no needed tile — possible only where the q and
+    k lengths differ — is refused rather than left unwritten."""
+    from repro.kernels import flash_attention as fa
+
+    def needed(qi, ki):
+        return fa._block_needed(qi * fa.BQ, ki * fa.BK, causal, window)
+
+    if rep:
+        want = [(qi, ki, r) for ki in range(nk) for r in range(rep)
+                for qi in range(nq) if needed(qi, ki)]
+        n_out, out_of = nk, lambda e: e[1]
+    else:
+        want = [(qi, ki, 0) for qi in range(nq) for ki in range(nk)
+                if needed(qi, ki)]
+        n_out, out_of = nq, lambda e: e[0]
+    if {out_of(e) for e in want} != set(range(n_out)):
+        assert nq != nk
+        with pytest.raises(ValueError):
+            fa._tile_table(nq, nk, causal, window, rep=rep)
+        return
+    tbl = fa._tile_table(nq, nk, causal, window, rep=rep)
+    assert tbl.dtype == np.int32
+    got = list(zip(np.asarray(fa._qi(tbl)).tolist(),
+                   np.asarray(fa._ki(tbl)).tolist(),
+                   np.asarray(fa._r(tbl)).tolist()))
+    assert got == want
+    first, last = np.asarray(fa._first(tbl)), np.asarray(fa._last(tbl))
+    for b in range(n_out):
+        idx = [i for i, e in enumerate(got) if out_of(e) == b]
+        assert idx == list(range(idx[0], idx[-1] + 1))   # one run
+        assert np.flatnonzero(first[idx]).tolist() == [0]
+        assert np.flatnonzero(last[idx]).tolist() == [len(idx) - 1]
+    if nq == nk and causal and not window:
+        assert tbl.size == max(rep, 1) * nq * (nq + 1) // 2
+
+
+def _doc_ids(B, S, bounds):
+    """Non-decreasing doc ids that change at each of ``bounds``."""
+    ids = jnp.searchsorted(jnp.asarray(bounds), jnp.arange(S), side="right")
+    return jnp.broadcast_to(ids.astype(jnp.int32)[None], (B, S))
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 300),
+                                           (False, 0), (False, 700)])
+def test_flash_attention_nq4(causal, window, packed):
+    """S=1024 (nq = nk = 4) with GQA (4, 2): the causal table skips 6 of
+    16 tiles, a window drops whole tiles beside the diagonal too, and packed
+    rows (documents ending off the tile edges) add the runtime segment skip
+    on top. Forward and all three gradients against the reference."""
+    B, S, H, K, D = 1, 1024, 4, 2, 32
+    q = jax.random.normal(KEY, (B, S, H, D))
+    k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, S, K, D))
+    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, S, K, D))
+    seg = _doc_ids(B, S, [300, 512, 700]) if packed else None
+
+    def got(q, k, v):
+        return ops.flash_attention(q, k, v, segments=seg, causal=causal,
+                                   window=window)
+
+    def want(q, k, v):
+        return ref.flash_attention_ref(q, k, v, segments=seg, causal=causal,
+                                       window=window)
+
+    np.testing.assert_allclose(np.asarray(got(q, k, v)),
+                               np.asarray(want(q, k, v)), atol=5e-6)
+    _grad_pair(got, want, q, k, v, 1e-4)
+
+
+def _grid_records():
+    from repro import obs
+    return {r.attrs["kernel"]: (r.attrs["launched"], r.attrs["dense"])
+            for r in obs.spans() if r.name == "flash.grid"}
+
+
+def test_flash_grid_recorded_by_traced_calls():
+    """Tracing the kernels records each call's grid steps: a tiny causal
+    trainer run launches fewer than the dense walk in all three kernels
+    (3 of 4 tiles at nq = nk = 2); a non-causal call launches all."""
+    from repro import obs
+    from repro.core.precision import TriAccelConfig
+    from repro.models.lm import LMConfig
+    from repro.nn.attention import AttnConfig
+    from repro.nn.blocks import BlockDef, StackConfig
+    from repro.train.trainer import Trainer, TrainerConfig
+
+    # shapes no other test traces, so the kernels' jit caches miss
+    attn = AttnConfig(d_model=24, num_heads=3, num_kv_heads=1, head_dim=8,
+                      impl="flash")
+    sc = StackConfig(segments=(((BlockDef("gqa", "dense"),), 1),),
+                     d_model=24, d_ff=48, attn=attn, remat=False)
+    cfg = LMConfig(name="tiny-flash", family="dense", vocab_size=64,
+                   stack=sc, compute_dtype=jnp.float32)
+    tac = TriAccelConfig(ladder="tpu", t_ctrl=2, enable_curvature=False)
+    obs.clear()
+    tr = Trainer(cfg, tac, TrainerConfig(total_steps=2, seq_len=512,
+                                         rungs=(2,)))
+    tr.warm_rungs()
+    tr.run(1)
+    grids = _grid_records()
+    assert set(grids) == {"fwd", "dq", "dkv"}, grids
+    B, H, K = 2, 3, 1
+    assert grids["fwd"] == grids["dq"] == (B * H * 3, B * H * 4)
+    assert grids["dkv"] == (B * K * 3 * 3, B * K * 3 * 4)
+
+    obs.clear()
+    q = jax.random.normal(KEY, (1, 512, 3, 8))
+    kv = jax.random.normal(jax.random.fold_in(KEY, 1), (1, 512, 1, 8))
+    jax.jit(jax.grad(lambda q: jnp.sum(ops.flash_attention(
+        q, kv, kv, causal=False))))(q)
+    grids = _grid_records()
+    assert set(grids) == {"fwd", "dq", "dkv"}, grids
+    assert all(launched == dense for launched, dense in grids.values())
